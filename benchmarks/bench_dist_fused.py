@@ -91,8 +91,7 @@ state = init_dist_state(
 step = make_distributed_step(mesh, dcfg, ecfg)
 lowered = step.lower(state)   # lowered once: compiled for costs, text for sorts
 compiled = lowered.compile()
-from repro.launch.dryrun import cost_analysis_dict
-ca = cost_analysis_dict(compiled)
+ca = compiled.cost_analysis()
 out = {
     "bytes_accessed": float(ca["bytes accessed"]),
     "flops": float(ca.get("flops", 0.0)),

@@ -59,8 +59,6 @@ RADIUS = 6.25  # -> 16^3 cells at SPACE=100: ~2 agents/cell mean at N=8192
 
 def _bytes_accessed(jitted, *args):
     ca = jitted.lower(*args).compile().cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0]
     return float(ca["bytes accessed"])
 
 

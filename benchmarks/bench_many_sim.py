@@ -80,8 +80,6 @@ def _batched_bytes(eng, b: int, n_steps: int) -> float:
         bstate, n_steps=n_steps, observables=eng._obs_triples() or None
     )
     ca = lowered.compile().cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0]
     return float(ca["bytes accessed"])
 
 
@@ -90,8 +88,6 @@ def _solo_bytes(built, n_steps: int) -> float:
         built.state, n_steps=n_steps, observables=built._obs_triples() or None
     )
     ca = lowered.compile().cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0]
     return float(ca["bytes accessed"])
 
 

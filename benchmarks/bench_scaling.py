@@ -24,7 +24,7 @@ import jax, jax.numpy as jnp
 import numpy as np
 from repro.core import EngineConfig, ForceParams, brownian_motion
 from repro.core.distributed import DomainConfig, init_dist_state, make_distributed_step
-from repro.launch.dryrun import collective_bytes_from_hlo, cost_analysis_dict, _strip_done_ops
+from repro.launch.dryrun import collective_bytes_from_hlo, _strip_done_ops
 
 mx, my = %(mx)d, %(my)d
 from repro.launch.mesh import make_mesh
@@ -45,7 +45,7 @@ step = make_distributed_step(mesh, dcfg, ecfg)
 lowered = step.lower(state)
 compiled = lowered.compile()
 coll = collective_bytes_from_hlo(_strip_done_ops(compiled.as_text()))
-ca = cost_analysis_dict(compiled)
+ca = compiled.cost_analysis()
 print(json.dumps({"ndev": mx*my, "coll": coll, "flops": ca.get("flops", 0.0)}))
 """
 

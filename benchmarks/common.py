@@ -60,13 +60,11 @@ def print_table(title: str, rows, headers):
 def bytes_and_sorts(jitted, *args):
     """(bytes accessed, HLO sort-op count) from ONE lowering of a jitted
     callable — the shared compile-only probe behind the smoke tier's
-    lowering guards (no execution; cost_analysis may return a list)."""
+    lowering guards (no execution)."""
     from repro.core.distributed import hlo_sort_count
 
     lowered = jitted.lower(*args)
     ca = lowered.compile().cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0]
     return float(ca["bytes accessed"]), hlo_sort_count(lowered.as_text())
 
 

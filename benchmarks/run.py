@@ -30,6 +30,8 @@ import sys
 import time
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from . import (
     bench_ablation,
     bench_complexity,
@@ -88,4 +90,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

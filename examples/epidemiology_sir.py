@@ -47,6 +47,7 @@ from repro.core import (
     sir_infection,
     sir_recovery,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import pso
 
 # Measles (paper Table 4.3): R0 = 12.9, recovery duration 8 days.
@@ -198,4 +199,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

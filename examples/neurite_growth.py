@@ -41,6 +41,7 @@ from repro import Simulation
 from repro.core import ForceParams, add_agents
 from repro.core.behaviors import StepContext
 from repro.core.diffusion import gradient_at
+from repro.launch.compile_cache import enable_compile_cache
 
 TRAIL, CONE = 0, 1
 
@@ -192,6 +193,7 @@ def main(n_neurons=16, steps=120, space=120.0, seed=0, smoke=False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny run for CI: build + step, skip the science bar")

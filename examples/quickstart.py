@@ -29,6 +29,7 @@ import numpy as np
 from repro import Simulation
 from repro.core import ForceParams, chemotaxis, concentration_at, secretion
 from repro.core.grid import build_index, candidate_neighbors
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def exposure_op(ctx, state):
@@ -57,24 +58,32 @@ def same_type_fraction(spec, pool) -> float:
     return float(jnp.sum(same) / jnp.maximum(jnp.sum(close), 1))
 
 
-def build_model(n_cells, space, seed) -> Simulation:
-    """The complete soma-clustering model, declared once (DESIGN.md §6)."""
+def build_model(n_cells, space, seed, max_per_cell=64, resolution=20,
+                **mechanics) -> Simulation:
+    """The complete soma-clustering model, declared once (DESIGN.md §6).
+
+    ``resolution`` is the substance grid's voxels per side (20 over the
+    default 100-unit space: 5-unit voxels); ``mechanics`` passes through to
+    :meth:`Simulation.mechanics` (e.g. ``impl="fused"``).
+    """
     rng = np.random.default_rng(seed)
     pos = rng.uniform(10, space - 10, (n_cells, 3)).astype(np.float32)
     kind = (rng.random(n_cells) < 0.5).astype(np.int32)
     return (
         Simulation(space=(0.0, space), cell_size=10.0, boundary="closed",
-                   dt=1.0, max_per_cell=64, seed=seed)
+                   dt=1.0, max_per_cell=max_per_cell, seed=seed)
         .add_agents(n_cells, position=pos, diameter=5.0, kind=kind, exposure=0.0)
-        .add_substance("substance_0", diffusion=4.0, decay=0.002, resolution=20)
-        .add_substance("substance_1", diffusion=4.0, decay=0.002, resolution=20)
+        .add_substance("substance_0", diffusion=4.0, decay=0.002,
+                       resolution=resolution)
+        .add_substance("substance_1", diffusion=4.0, decay=0.002,
+                       resolution=resolution)
         .use(
             secretion("substance_0", 1.0, kind=0),
             secretion("substance_1", 1.0, kind=1),
             chemotaxis("substance_0", 0.75, kind=0),
             chemotaxis("substance_1", 0.75, kind=1),
         )
-        .mechanics(ForceParams())
+        .mechanics(ForceParams(), **mechanics)
         .op(exposure_op, name="exposure", phase="post")
     )
 
@@ -111,6 +120,7 @@ def main(n_cells=600, steps=300, space=100.0, seed=0, smoke=False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny run for CI: build + step, skip the science bar")

@@ -21,6 +21,7 @@ import numpy as np
 from repro import training
 from repro.configs import get_config
 from repro.data import DataConfig, host_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
 from repro.optim import adamw
 
@@ -85,4 +86,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -31,6 +31,7 @@ from repro.core import (
     cell_division,
     growth,
 )
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def radial_census_op(center: float, frequency: int = 8) -> Operation:
@@ -113,6 +114,7 @@ def main(n_init=60, capacity=4096, steps=240, seed=0, smoke=False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny run for CI: build + step, skip the science bar")

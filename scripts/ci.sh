@@ -39,6 +39,9 @@
 # contract behind DomainConfig.overlap_halo.
 set -euo pipefail
 cd "$(dirname "$0")/.."
+# Every tier is a CPU tier: on a machine with a TPU, a parent holding the
+# chip would make every subprocess that reaches for it fail on libtpu's lock.
+export JAX_PLATFORMS=cpu
 
 echo "=== CI tier 0: test deps ==="
 # Property tests want the real hypothesis engine (pyproject `[test]` extra).

@@ -307,7 +307,6 @@ class Simulation:
         active_capacity: Optional[int] = None,
         tile: Optional[int] = None,
         overflow_fallback: bool = True,
-        interpret: bool = True,
         diffusion_impl: str = "reference",
         tile_order: str = "linear",
         morton_block: Optional[int] = None,
@@ -318,8 +317,8 @@ class Simulation:
 
         ``params=None`` disables the force/static-flag ops (the default when
         this method is never called).  ``impl``/``active_capacity``/``tile``/
-        ``overflow_fallback``/``interpret`` map onto the EngineConfig force
-        options; ``diffusion_impl`` selects the diffusion kernel.
+        ``overflow_fallback`` map onto the EngineConfig force options;
+        ``diffusion_impl`` selects the diffusion kernel.
         ``tile_order="morton"`` (fused impl, single-node) runs the
         Morton-window force kernel over the layout-sorted pool, with the
         ``morton_*`` knobs mapping onto their EngineConfig counterparts.
@@ -330,7 +329,6 @@ class Simulation:
             active_capacity=active_capacity,
             force_tile=tile,
             fused_overflow_fallback=overflow_fallback,
-            kernel_interpret=interpret,
             diffusion_impl=diffusion_impl,
             tile_order=tile_order,
             morton_block=morton_block,

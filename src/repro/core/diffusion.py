@@ -24,6 +24,8 @@ import jax.numpy as jnp
 
 Array = jax.Array
 
+DIFFUSION_IMPLS = ("reference", "pallas")
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
@@ -96,7 +98,12 @@ def _laplacian_zero_outside(u: Array, dx: float) -> Array:
 
 
 def diffuse(grid: DiffusionGrid, dt: float, impl: str = "reference") -> DiffusionGrid:
-    """One explicit central-difference step of Eq 4.3."""
+    """One explicit central-difference step of Eq 4.3 (``impl`` in
+    :data:`DIFFUSION_IMPLS`)."""
+    if impl not in DIFFUSION_IMPLS:
+        raise ValueError(
+            f"unknown diffusion impl {impl!r}; expected one of {DIFFUSION_IMPLS}"
+        )
     if impl == "pallas":
         from repro.kernels.diffusion3d import ops as d3_ops
 

@@ -64,7 +64,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import inspect
 import math
 from typing import Dict, Optional, Tuple
 
@@ -91,24 +90,12 @@ from .schedule import (
     seal,
 )
 
-try:  # JAX >= 0.6
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# Disable the replication checker where the installed jax exposes it
-# (check_rep on legacy, check_vma on new): it has no rule for pallas_call,
-# which the fused force path places inside the per-device step body.
-_SHARD_MAP_KW = {
-    flag: False
-    for flag in ("check_rep", "check_vma")
-    if flag in inspect.signature(_shard_map).parameters
-}
-
 
 def shard_map(f, mesh, in_specs, out_specs):
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **_SHARD_MAP_KW
+    # check_vma off: the varying-axes checker has no rule for pallas_call,
+    # which the fused force path places inside the per-device step body.
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
 
 from jax.sharding import PartitionSpec as P
@@ -163,6 +150,17 @@ class DomainConfig:
     def local_extent(self, dim: int) -> float:
         return self.extent if dim < self.n_decomposed else self.depth
 
+    @property
+    def codec_span(self) -> Tuple[float, float, float]:
+        """Per-dim range a halo codec's full-scale payload must span: every
+        local coordinate, ``[-halo, extent + halo]`` on decomposed dims and
+        ``[0, depth]`` on the rest."""
+        return tuple(
+            self.extent + 2 * self.halo_width if d < self.n_decomposed
+            else self.depth
+            for d in range(3)
+        )
+
     def ghost_capacity(self, pool_capacity: int) -> int:
         return pool_capacity + 2 * self.n_decomposed * self.halo_capacity
 
@@ -211,15 +209,15 @@ class HaloCodecState:
     send_ref: Array
     recv_ref: Array
     prev_ids: Array
-    scale: Array  # () f32
+    scale: Array  # (3,) f32 — per-dim quantization step
 
     @staticmethod
-    def create(n_dims: int, capacity: int, scale: float) -> "HaloCodecState":
+    def create(n_dims: int, capacity: int, scale) -> "HaloCodecState":
         return HaloCodecState(
             send_ref=jnp.zeros((n_dims, 2, capacity, 3), jnp.float32),
             recv_ref=jnp.zeros((n_dims, 2, capacity, 3), jnp.float32),
             prev_ids=jnp.full((n_dims, 2, capacity), -1, jnp.int32),
-            scale=jnp.asarray(scale, jnp.float32),
+            scale=jnp.broadcast_to(jnp.asarray(scale, jnp.float32), (3,)),
         )
 
 
@@ -404,7 +402,7 @@ def _slot_scales(
     needs the coarse escape."""
     if jnp.dtype(wire_dtype) == jnp.dtype(jnp.int16):
         return codec.scale
-    coarse = jnp.float32((dcfg.extent + 2.0 * dcfg.halo_width) / 127.0)
+    coarse = jnp.asarray(np.asarray(dcfg.codec_span) / 127.0, jnp.float32)
     fine = jnp.float32(dcfg.halo_width / 127.0)
     return jnp.where(fresh[:, None], coarse, fine)
 
@@ -696,9 +694,7 @@ def dist_env_build_op(dcfg: DomainConfig, ecfg: EngineConfig,
             g_alive = jnp.concatenate([pool.alive, gf.alive], axis=0)
         else:
             g_pos, g_rad, g_kind, g_alive = ctx.extras["halo_sources"]
-        index = build_index_arrays(
-            ecfg.spec, g_pos, g_alive, interpret=ecfg.kernel_interpret
-        )
+        index = build_index_arrays(ecfg.spec, g_pos, g_alive)
         ctx.index = index
         ctx.neighbors = NeighborContext.for_sources(
             ecfg.spec, index, state.pool, g_pos, g_rad, g_kind, g_alive
@@ -780,10 +776,7 @@ def interior_env_build_op(dcfg: DomainConfig, ecfg: EngineConfig) -> Operation:
     def fn(ctx: OpContext, state: DistState) -> DistState:
         pool = state.pool
         with jax.named_scope("interior_env_build"):
-            index = build_index_arrays(
-                ecfg.spec, pool.position, pool.alive,
-                interpret=ecfg.kernel_interpret,
-            )
+            index = build_index_arrays(ecfg.spec, pool.position, pool.alive)
             interior, shell = interior_shell_masks(
                 dcfg, ecfg.spec, pool.position, pool.alive
             )
@@ -995,7 +988,7 @@ def init_dist_state(
         for name, g in base_grids.items()
         if name not in (stacked_grids or {})
     }
-    scale = (dcfg.extent + 2 * dcfg.halo_width) / 32767.0
+    scale = np.asarray(dcfg.codec_span, np.float32) / 32767.0
     codec = HaloCodecState.create(dcfg.n_decomposed, dcfg.halo_capacity, scale)
     codec = jax.tree.map(lambda x: jnp.stack([x] * n_dev), codec)
 

@@ -33,7 +33,8 @@ import jax.numpy as jnp
 from . import diffusion as dgrid
 from .agents import AgentPool
 from .behaviors import Behavior
-from .forces import ForceParams
+from .diffusion import DIFFUSION_IMPLS
+from .forces import FORCE_IMPLS, TILE_ORDERS, ForceParams
 from .grid import GridSpec
 from .schedule import HealthReport, Scheduler, empty_health
 
@@ -55,8 +56,8 @@ class EngineConfig:
     diffusion_frequency: int = 1                     # §4.4.4 multi-scale
     active_capacity: Optional[int] = None            # §5.5 work compaction
     force_tile: Optional[int] = None                 # tile-wise force eval
-    force_impl: str = "reference"                    # reference | pallas | fused
-    diffusion_impl: str = "reference"
+    force_impl: str = "reference"                    # FORCE_IMPLS
+    diffusion_impl: str = "reference"                # DIFFUSION_IMPLS
     # "fused" only: lax.cond back to the dense candidate path when a cell
     # overflows max_per_cell (cell-list truncation would drop pair forces).
     # Disable only when max_per_cell is a guaranteed bound; that keeps the
@@ -72,16 +73,25 @@ class EngineConfig:
     # (morton_window_fallback; disable only for compile-cost benchmarks on
     # known-sorted layouts).  block/window default per pool size — see
     # repro.kernels.cell_force.ops.window_defaults.
-    tile_order: str = "linear"                       # linear | morton
+    tile_order: str = "linear"                       # TILE_ORDERS
     morton_block: Optional[int] = None
     morton_window: Optional[int] = None
     morton_window_fallback: bool = True
-    # Pallas interpret mode for the kernel force impls (CPU-container
-    # default; set False on TPU hardware for the Mosaic lowering).
-    kernel_interpret: bool = True
     # Health-telemetry op frequency (DESIGN.md §7): fold saturation /
     # non-finite detection into state.health every k steps (0 disables).
     health_frequency: int = 1
+
+    def __post_init__(self):
+        for name, choices in (
+            ("force_impl", FORCE_IMPLS),
+            ("diffusion_impl", DIFFUSION_IMPLS),
+            ("tile_order", TILE_ORDERS),
+        ):
+            value = getattr(self, name)
+            if value not in choices:
+                raise ValueError(
+                    f"unknown {name} {value!r}; expected one of {choices}"
+                )
 
 
 @jax.tree_util.register_dataclass

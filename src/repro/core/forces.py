@@ -34,6 +34,9 @@ from .neighbors import NeighborContext
 
 Array = jax.Array
 
+FORCE_IMPLS = ("reference", "pallas", "fused")
+TILE_ORDERS = ("linear", "morton")
+
 
 def _morton_window_ok(
     spec: GridSpec,
@@ -220,7 +223,6 @@ def mechanical_forces(
     impl: str = "reference",
     neighbors: Optional[NeighborContext] = None,
     fused_fallback: bool = True,
-    interpret: bool = True,
     tile: Optional[int] = None,
     tile_order: str = "linear",
     morton_block: Optional[int] = None,
@@ -258,11 +260,11 @@ def mechanical_forces(
     ``fused_fallback`` guards the fused path's cell-list truncation: when
     any cell overflowed ``max_per_cell`` a ``lax.cond`` re-evaluates through
     the reference candidate path (correctness first, like the §5.5
-    compaction fallback below).  ``interpret`` selects Pallas interpret mode
-    for the kernel impls (the CPU-container default; pass False on TPU for
-    the Mosaic lowering).  ``tile``: evaluate the dense candidate path in
-    agent tiles of this size (bounds the (tile, K, 3) working set; applies
-    to the reference impl and the fused path's overflow fallback).
+    compaction fallback below).  The kernel impls pick Pallas interpret
+    mode from the backend (:func:`repro.kernels.interpret_default`).
+    ``tile``: evaluate the dense candidate path in agent tiles of this size
+    (bounds the (tile, K, 3) working set; applies to the reference impl and
+    the fused path's overflow fallback).
 
     ``tile_order="morton"`` (fused impl, single-node sources only): run the
     Morton-window kernel of `repro.kernels.cell_force` — storage-order tiles
@@ -283,6 +285,14 @@ def mechanical_forces(
     nowhere outside the overflow-fallback branch and per-step neighbor
     traffic follows the number of *moving* agents, the paper's §5.5 intent.
     """
+    if impl not in FORCE_IMPLS:
+        raise ValueError(
+            f"unknown force impl {impl!r}; expected one of {FORCE_IMPLS}"
+        )
+    if tile_order not in TILE_ORDERS:
+        raise ValueError(
+            f"unknown tile_order {tile_order!r}; expected one of {TILE_ORDERS}"
+        )
     if neighbors is None:
         neighbors = NeighborContext.for_pool(spec, index, pool)
     radius = pool.radius()
@@ -326,7 +336,6 @@ def mechanical_forces(
         dense = lambda: pf_ops.pairwise_force(
             pool.position, radius, *neighbors.candidates(),
             k=params.repulsion_k, gamma=params.attraction_gamma,
-            interpret=interpret,
             all_position=src_pos, all_radius=src_rad,
         )
     elif impl == "fused":
@@ -335,14 +344,13 @@ def mechanical_forces(
         fused = lambda: cf_ops.cell_list_force(
             src_pos, src_rad, index.cell_list, spec.dims,
             k=params.repulsion_k, gamma=params.attraction_gamma,
-            interpret=interpret, num_out=c,
+            num_out=c,
         )
         if tile_order == "morton" and src_pos is pool.position:
             morton_eval = lambda: cf_ops.cell_window_force(
                 pool.position, radius, index.cell_of_agent, spec.dims,
                 k=params.repulsion_k, gamma=params.attraction_gamma,
                 block=morton_block, window=morton_window,
-                interpret=interpret,
             )
             if morton_fallback:
                 ok = _morton_window_ok(

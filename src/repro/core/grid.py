@@ -30,6 +30,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.cell_rank import ops as cr_ops
+
 from . import morton
 from .agents import AgentPool, permute, permute_to
 
@@ -46,11 +48,19 @@ class GridSpec:
     dims: Tuple[int, int, int] = dataclasses.field(metadata=dict(static=True))
     max_per_cell: int = dataclasses.field(metadata=dict(static=True))
     use_morton: bool = dataclasses.field(metadata=dict(static=True), default=True)
-    # Within-cell ranking impl for the build stage ("xla" | "pallas"),
-    # selected like EngineConfig.force_impl: "xla" is the pure-XLA
-    # tiled-histogram fallback (interpret-safe, container/test default),
-    # "pallas" the repro.kernels.cell_rank VMEM-histogram kernel for TPU.
+    # Within-cell ranking impl for the build stage (cr_ops.IMPLS), selected
+    # like EngineConfig.force_impl: "xla" is the pure-XLA tiled-histogram
+    # pass (the default, and the only one that compiles for TPU today),
+    # "pallas" the repro.kernels.cell_rank VMEM-histogram kernel, whose
+    # (L, 1) block Mosaic does not accept yet.
     rank_impl: str = dataclasses.field(metadata=dict(static=True), default="xla")
+
+    def __post_init__(self):
+        if self.rank_impl not in cr_ops.IMPLS:
+            raise ValueError(
+                f"unknown rank_impl {self.rank_impl!r}; expected one of "
+                f"{cr_ops.IMPLS}"
+            )
 
     @property
     def n_cells(self) -> int:
@@ -118,7 +128,6 @@ def layout_rank_table(spec: GridSpec) -> Array:
 def sort_agents(
     spec: GridSpec,
     pool: AgentPool,
-    interpret: bool = True,
     rank_tile: int | None = None,
 ) -> AgentPool:
     """§5.4.2 agent sorting: reorder the pool along the space-filling curve.
@@ -156,14 +165,11 @@ def sort_agents(
     cid = jnp.where(pool.alive, linear_cell_id(spec, ijk), n_cells)  # (C,)
     zid = layout_rank_table(spec)[cid]  # rank of the agent's cell in Z-order
 
-    from repro.kernels.cell_rank import ops as cr_ops
-
     rank = cr_ops.cell_rank(
         zid,
         n_cells=n_cells,
         impl=spec.rank_impl,
         tile=rank_tile,
-        interpret=interpret,
     )
     counts = jnp.zeros((n_cells + 1,), jnp.int32).at[zid].add(1)
     offsets = jnp.cumsum(counts) - counts  # exclusive scan in Z-order
@@ -190,7 +196,6 @@ def build_index_arrays(
     spec: GridSpec,
     position: Array,
     alive: Array,
-    interpret: bool = True,
     rank_tile: int | None = None,
     assume_sorted: bool = False,
 ) -> GridIndex:
@@ -211,10 +216,8 @@ def build_index_arrays(
          scan over tiles → intra-tile ranks; impl per ``spec.rank_impl``);
       3. scatter agent indices into ``cell_list[cell, rank]`` (O(C)).
 
-    ``interpret`` selects Pallas interpret mode for ``rank_impl="pallas"``
-    (the engines pass ``EngineConfig.kernel_interpret``); ``rank_tile``
-    overrides the ≈√n_cells rank tile (tests keep interpret-mode grids
-    coarse with it).
+    ``rank_tile`` overrides the ≈√n_cells rank tile (tests keep
+    interpret-mode grids coarse with it).
 
     ``assume_sorted`` promises the arrays are already layout-sorted — i.e.
     :func:`sort_agents` ran on this exact pool with this exact spec and
@@ -238,14 +241,11 @@ def build_index_arrays(
         start_ext = jnp.concatenate([start, jnp.zeros((1,), jnp.int32)])
         rank = jnp.arange(c, dtype=jnp.int32) - start_ext[cid]
     else:
-        from repro.kernels.cell_rank import ops as cr_ops
-
         rank = cr_ops.cell_rank(
             cid,
             n_cells=n_cells,
             impl=spec.rank_impl,
             tile=rank_tile,
-            interpret=interpret,
         )
     overflowed = jnp.any(cell_count > spec.max_per_cell)
 
@@ -269,7 +269,6 @@ def build_index_arrays(
 def build_index(
     spec: GridSpec,
     pool: AgentPool,
-    interpret: bool = True,
     rank_tile: int | None = None,
     assume_sorted: bool = False,
 ) -> GridIndex:
@@ -277,7 +276,6 @@ def build_index(
         spec,
         pool.position,
         pool.alive,
-        interpret=interpret,
         rank_tile=rank_tile,
         assume_sorted=assume_sorted,
     )

@@ -330,9 +330,7 @@ def sort_op(config) -> Operation:
     def fn(ctx: OpContext, state):
         return dataclasses.replace(
             state,
-            pool=sort_agents(
-                config.spec, state.pool, interpret=config.kernel_interpret
-            ),
+            pool=sort_agents(config.spec, state.pool),
         )
 
     return Operation(
@@ -356,7 +354,6 @@ def env_build_op(config) -> Operation:
         index = build_index(
             config.spec,
             state.pool,
-            interpret=config.kernel_interpret,
             assume_sorted=config.sort_frequency == 1,
         )
         ctx.index = index
@@ -432,7 +429,6 @@ def force_pass(config, ctx: OpContext, state, *, index=None, neighbors=None,
                 impl=config.force_impl,
                 neighbors=use_neighbors,
                 fused_fallback=config.fused_overflow_fallback,
-                interpret=config.kernel_interpret,
                 tile=config.force_tile,
                 tile_order=config.tile_order,
                 morton_block=config.morton_block,

@@ -1,7 +1,8 @@
 """Pallas TPU kernels for the perf-critical compute layers.
 
 Each kernel package has:
-  kernel.py — pl.pallas_call + BlockSpec tiling (TPU target, interpret-validated)
+  kernel.py — pl.pallas_call + BlockSpec tiling (Mosaic on TPU; the Pallas
+              interpreter on CPU — see :func:`interpret_default`)
   ops.py    — jit'd dispatch wrapper (impl="pallas" | "reference" | …)
   ref.py    — pure-jnp oracle
 
@@ -15,3 +16,21 @@ Kernels:
   flash_attention — online-softmax attention for the LM stack (GQA/causal/window)
   rmsnorm         — fused residual-stream normalization (one read, one write)
 """
+
+from __future__ import annotations
+
+import jax
+
+
+def interpret_default(interpret: bool | None = None) -> bool:
+    """Pallas interpret mode for one ``pallas_call``.
+
+    An explicit ``interpret`` wins (compile-only tests pass ``False`` to
+    lower for a described TPU from a CPU process).  ``None`` — every
+    kernel's default — means "interpret iff JAX's default backend is the
+    CPU", read when the kernel is traced, so on a TPU no kernel runs through
+    the interpreter and on the CPU no kernel asks for Mosaic.
+    """
+    if interpret is not None:
+        return interpret
+    return jax.default_backend() == "cpu"

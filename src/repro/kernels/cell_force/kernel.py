@@ -30,9 +30,10 @@ offsets (including the row-major wrap-around a linear shift would otherwise
 alias to the wrong cell).  Self-interaction is the (i == j) diagonal of the
 center offset at dz = 0 — one static mask, no id comparison.
 
-Validated in interpret mode against ref.py (CPU container); on TPU hardware
-the same code lowers through Mosaic.  VMEM per program is O(nz·M) block rows
-plus O(nz·M²) pair temporaries.
+Validated in interpret mode against ref.py on the CPU; on a TPU the same
+code lowers through Mosaic (tests/test_tpu_compile.py compiles it for a
+described v5e).  VMEM per program is O(nz·M) block rows plus O(nz·M²)
+pair temporaries.
 
 Distributed adoption (§6.2.1, DESIGN.md §4): the kernel is oblivious to the
 local/ghost split — the distributed engine builds the cell list over its
@@ -50,6 +51,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import interpret_default
 
 Array = jax.Array
 
@@ -104,19 +107,21 @@ def _cell_force_kernel(
     qy = qpos_ref[1, 0]
     qz = qpos_ref[2, 0]
     qr = qrad_ref[0, 0]
-    qv = qval_ref[0, 0] != 0
+    qv = qval_ref[0, 0].astype(jnp.float32)
 
     npx = npos_ref[0, 0]
     npy = npos_ref[1, 0]
     npz = npos_ref[2, 0]
     nr = nrad_ref[0, 0]
-    nv = nval_ref[0, 0] != 0
+    nv = nval_ref[0, 0].astype(jnp.float32)
 
+    # Masks are built by comparing f32/int32 operands after they are
+    # broadcast: Mosaic cannot reshape boolean vectors (no i1 shape casts).
     zs = jax.lax.broadcasted_iota(jnp.int32, (nz, 1, 1), 0)
-    row = jax.lax.broadcasted_iota(jnp.int32, (m, m), 0)
-    clm = jax.lax.broadcasted_iota(jnp.int32, (m, m), 1)
-    diag = row == clm                          # (M, M) self slot
+    diag = (jax.lax.broadcasted_iota(jnp.int32, (1, m, m), 1)
+            == jax.lax.broadcasted_iota(jnp.int32, (1, m, m), 2))  # self slot
     is_center = off == 4                       # dx = dy = 0
+    q_ok = qv[:, :, None] > 0.0                # (nz, M, 1) → query occupied
 
     acc_x = jnp.zeros((nz, m), jnp.float32)
     acc_y = jnp.zeros((nz, m), jnp.float32)
@@ -129,10 +134,10 @@ def _cell_force_kernel(
         sr = _shift_z(nr, dz)[:, None, :]
         sv = _shift_z(nv, dz)[:, None, :]
 
-        pair = qv[:, :, None] & sv & ((zs + dz >= 0) & (zs + dz < nz)) & xy_ok
+        pair = q_ok & (sv > 0.0) & (zs + dz >= 0) & (zs + dz < nz) & xy_ok
         if dz == 0:
             # Self-pair: same cell, same slot — only at the center offset.
-            pair = pair & ~(diag[None, :, :] & is_center)
+            pair = pair & ~(diag & is_center)
 
         dxc = qx[:, :, None] - sx              # (nz, M, M)
         dyc = qy[:, :, None] - sy
@@ -161,7 +166,7 @@ def cell_list_force_planar(
     dims: tuple,    # (nx, ny, nz) static grid dims
     k: float = 2.0,
     gamma: float = 1.0,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> Array:
     """Per-slot net force, (3, n_cols, nz, M).
 
@@ -196,7 +201,7 @@ def cell_list_force_planar(
         ],
         out_specs=pl.BlockSpec((3, 1, nz, m), lambda i, o: (0, i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((3, n_cols, nz, m), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_default(interpret),
     )(cpos, crad, cval, cpos, crad, cval)
 
 
@@ -252,8 +257,9 @@ def _window_force_kernel(
         & (jnp.abs(qcz[:, None] - wcz[None, :]) <= 1)
         & (qg != wg)
         & ok_w
-        & (qcid < n_cells)[:, None]
-        & (wcid < n_cells)[None, :]
+        # Compare after the broadcast: Mosaic has no boolean shape casts.
+        & (qcid[:, None] < n_cells)
+        & (wcid[None, :] < n_cells)
     )
 
     dx = qx[:, None] - wx[None, :]             # (T, BW)
@@ -290,7 +296,7 @@ def cell_window_force_planar(
     gamma: float = 1.0,
     block: int = 128,
     half_window: int = 8,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> Array:
     """Morton-window contact forces over a layout-sorted pool, (4, C).
 
@@ -338,5 +344,5 @@ def cell_window_force_planar(
         ],
         out_specs=pl.BlockSpec((4, t), qry_idx),
         out_shape=jax.ShapeDtypeStruct((4, c), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_default(interpret),
     )(ppos, pcid, ppos, pcid)

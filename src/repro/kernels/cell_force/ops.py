@@ -66,7 +66,7 @@ def cell_list_force(
     k: float = 2.0,
     gamma: float = 1.0,
     impl: str = "pallas",
-    interpret: bool = True,
+    interpret: bool | None = None,
     num_out: int | None = None,
 ) -> Array:
     """Net Eq-4.1 force per agent, (num_out, 3), straight from the cell list.
@@ -136,7 +136,7 @@ def cell_window_force(
     gamma: float = 1.0,
     block: int | None = None,
     window: int | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> Array:
     """Net Eq-4.1 force per agent, (C, 3), via the Morton-window kernel.
 

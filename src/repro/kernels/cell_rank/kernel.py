@@ -39,6 +39,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_default
+
 Array = jax.Array
 
 
@@ -75,7 +77,7 @@ def _rank_kernel(cid_ref, out_ref, hist_ref):
 
 
 def cell_rank_tiled(
-    cid_cols: Array, hist_width: int, interpret: bool = True
+    cid_cols: Array, hist_width: int, interpret: bool | None = None
 ) -> Array:
     """Within-cell ranks for tile-column-major cell ids.
 
@@ -92,5 +94,5 @@ def cell_rank_tiled(
         out_specs=pl.BlockSpec((l, 1), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((l, t), jnp.int32),
         scratch_shapes=[pltpu.VMEM((1, hist_width), jnp.int32)],
-        interpret=interpret,
+        interpret=interpret_default(interpret),
     )(cid_cols)

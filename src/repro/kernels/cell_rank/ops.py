@@ -69,7 +69,7 @@ def cell_rank(
     n_cells: int,
     impl: str = "xla",
     tile: int | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> Array:
     """``rank[i] = |{j < i : cid[j] == cid[i]}|`` — sort-free, (C,) int32.
 
@@ -77,8 +77,8 @@ def cell_rank(
     dead-agent sentinel; sentinel rows rank among themselves, harmless —
     the build masks them out).  ``tile`` overrides the ≈√NC tile length
     (tests pass small inputs a coarse tile so the interpret-mode Pallas
-    grid stays a handful of programs).  ``interpret`` selects Pallas
-    interpret mode (CPU-container default; False on TPU for Mosaic).
+    grid stays a handful of programs).  ``interpret`` defaults to
+    :func:`repro.kernels.interpret_default` (the backend decides).
     """
     if impl not in IMPLS:
         raise ValueError(f"unknown cell_rank impl {impl!r}; expected {IMPLS}")

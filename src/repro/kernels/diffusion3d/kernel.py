@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_default
+
 Array = jax.Array
 
 
@@ -39,7 +41,7 @@ def _stencil_kernel(u_ref, xm_ref, xp_ref, ym_ref, yp_ref, zm_ref, zp_ref, o_ref
 )
 def diffusion_step_pallas(
     u: Array, nu_dt_dx2: float, decay_dt: float,
-    interpret: bool = True, tile_x: int = 8,
+    interpret: bool | None = None, tile_x: int = 8,
 ) -> Array:
     nx, ny, nz = u.shape
     z = jnp.pad(u, 1)
@@ -64,6 +66,6 @@ def diffusion_step_pallas(
         in_specs=[spec] * 7,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((nxp, ny, nz), u.dtype),
-        interpret=interpret,
+        interpret=interpret_default(interpret),
     )(*args)
     return out[:nx]

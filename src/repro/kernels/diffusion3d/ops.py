@@ -20,7 +20,7 @@ def diffusion_step(
     nu_dt_dx2: float,
     decay_dt: float = 0.0,
     impl: str = "pallas",
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> Array:
     """One Eq-4.3 step.  impl: "pallas" | "reference"."""
     if impl == "reference":

@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_default
+
 Array = jax.Array
 
 NEG_INF = -1e30
@@ -122,7 +124,7 @@ def flash_attention_flat(
     kv_len: int,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> Array:
     bh, tq, d = q.shape
     bhkv, tk, _ = k.shape
@@ -165,6 +167,6 @@ def flash_attention_flat(
             jax.ShapeDtypeStruct((bh, tq), jnp.float32),
             jax.ShapeDtypeStruct((bh, tq), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_default(interpret),
     )(q, k, v)
     return out

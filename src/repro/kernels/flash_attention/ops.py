@@ -109,7 +109,7 @@ def flash_attention(
     kv_offset: int = 0,
     scale: Optional[float] = None,
     impl: str = "pallas",
-    interpret: bool = True,
+    interpret: bool | None = None,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
     unroll: bool = False,

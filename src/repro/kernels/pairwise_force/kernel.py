@@ -28,6 +28,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_default
+
 Array = jax.Array
 
 TILE_N = 128
@@ -90,7 +92,7 @@ def pairwise_force_planar(
     cand_mask: Array,  # (1, N, K) int8
     k: float = 2.0,
     gamma: float = 1.0,
-    interpret: bool = True,
+    interpret: bool | None = None,
     tile_n: int = TILE_N,
     tile_k: int = TILE_K,
 ) -> Array:
@@ -116,5 +118,5 @@ def pairwise_force_planar(
         ],
         out_specs=pl.BlockSpec((3, tile_n), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((3, n), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_default(interpret),
     )(pos, rad, cand_pos, cand_rad, cand_mask)

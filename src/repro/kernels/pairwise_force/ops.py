@@ -38,7 +38,7 @@ def pairwise_force(
     k: float = 2.0,
     gamma: float = 1.0,
     impl: str = "pallas",
-    interpret: bool = True,
+    interpret: bool | None = None,
     all_position: Array | None = None,  # (S, 3) candidate sources (default: queries)
     all_radius: Array | None = None,    # (S,)
 ) -> Array:

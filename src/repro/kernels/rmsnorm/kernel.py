@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_default
+
 Array = jax.Array
 
 TILE_ROWS = 256
@@ -32,7 +34,7 @@ def rmsnorm_rows(
     x: Array,          # (N, D)
     scale: Array,      # (D,)
     eps: float = 1e-6,
-    interpret: bool = True,
+    interpret: bool | None = None,
     tile_rows: int = TILE_ROWS,
 ) -> Array:
     n, d = x.shape
@@ -47,6 +49,6 @@ def rmsnorm_rows(
         ],
         out_specs=pl.BlockSpec((tile_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n + pad, d), x.dtype),
-        interpret=interpret,
+        interpret=interpret_default(interpret),
     )(xp, scale)
     return out[:n]

@@ -18,7 +18,7 @@ def rmsnorm(
     scale: Array,      # (D,)
     eps: float = 1e-6,
     impl: str = "pallas",
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> Array:
     if impl == "reference":
         return rmsnorm_ref(x, scale, eps)

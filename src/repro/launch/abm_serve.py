@@ -44,6 +44,8 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 @dataclasses.dataclass
 class SessionRequest:
@@ -274,4 +276,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

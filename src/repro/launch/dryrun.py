@@ -285,18 +285,9 @@ class SkipCell(Exception):
     pass
 
 
-def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` across jax versions (older jax returns a
-    one-element list of dicts, newer a plain dict)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0]
-    return cost
-
-
 def _cell_costs(lowered) -> Dict[str, float]:
     compiled = lowered.compile()
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     hlo = _strip_done_ops(compiled.as_text())
     coll = collective_bytes_from_hlo(hlo)
     return {
@@ -399,7 +390,7 @@ def lower_teraagent(mesh):
         send_ref=sds((n_dev, len(axes), 2, halo_cap, 3), jnp.float32),
         recv_ref=sds((n_dev, len(axes), 2, halo_cap, 3), jnp.float32),
         prev_ids=sds((n_dev, len(axes), 2, halo_cap), jnp.int32),
-        scale=sds((n_dev,), jnp.float32),
+        scale=sds((n_dev, 3), jnp.float32),
     )
     from repro.core.schedule import HealthReport
 

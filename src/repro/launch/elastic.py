@@ -157,7 +157,7 @@ def grow_dist_state(state, new_capacity: int, new_dcfg):
     from repro.core.schedule import empty_health
 
     n_dev = state.pool.position.shape[0]
-    scale = float(np.asarray(jax.device_get(state.codec.scale)).ravel()[0])
+    scale = np.asarray(jax.device_get(state.codec.scale))[0]
     codec1 = HaloCodecState.create(
         new_dcfg.n_decomposed, new_dcfg.halo_capacity, scale
     )

@@ -12,25 +12,15 @@ from __future__ import annotations
 import jax
 
 
-def _compat_make_mesh(shape, axes):
-    """``jax.make_mesh`` across jax versions: ``axis_types`` (and the
-    ``jax.sharding.AxisType`` enum itself) only exist on newer releases; the
-    default axis type is Auto everywhere, so omitting it is equivalent."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            tuple(shape), tuple(axes),
-            axis_types=(axis_type.Auto,) * len(shape),
-        )
-    return jax.make_mesh(tuple(shape), tuple(axes))
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh (tests / small dry-runs)."""
-    return _compat_make_mesh(shape, axes)
+    """Mesh of ``shape`` over ``jax.devices()`` with Auto-sharded axes."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(shape),
+    )

@@ -27,10 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 Array = jax.Array
 
@@ -84,7 +80,7 @@ def make_compressed_grad_allreduce(mesh, wire_dtype=jnp.int8, axis_names=("data"
             jax.tree.unflatten(treedef, [o[1] for o in outs]),
         )
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), P()),

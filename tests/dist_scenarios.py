@@ -1030,6 +1030,47 @@ def scenario_diffusion_uneven_parity():
     print("diffusion uneven parity OK")
 
 
+def scenario_codec_full_depth():
+    """A cube on a 2x2 mesh: the non-decomposed depth (48) is larger than
+    the halo-extended decomposed extent (24 + 2·2).  The int16 halo codec
+    must span the depth too — ghosts above z = 28 used to saturate there,
+    piling into one cell layer and feeding wrong pair forces."""
+    from repro.core import Simulation
+
+    space = 48.0
+    mesh = _mesh((2, 2), ("data", "model"))
+    rng = np.random.default_rng(11)
+    pos = rng.uniform(4.0, space - 4.0, (4000, 3)).astype(np.float32)
+    finals = {}
+    for codec in ("int16", "none"):
+        dcfg = DomainConfig(
+            mesh_axes=("data", "model"), axis_sizes=(2, 2), extent=space / 2,
+            halo_width=2.0, halo_capacity=256, migrate_capacity=64,
+            depth=space, halo_codec=codec,
+        )
+        sim = (
+            Simulation(space=(0.0, space), cell_size=2.0, boundary="closed",
+                       dt=0.05, max_per_cell=32)
+            .add_agents(position=pos, diameter=1.6)
+            .mechanics(ForceParams())
+        )
+        st, _ = sim.distribute(mesh, dcfg, capacity=1500).run(2)
+        assert int(np.asarray(st.health.cell_overflow_steps).sum()) == 0
+        # Per-dim quantum: x/y span the halo-extended extent, z the depth.
+        np.testing.assert_allclose(
+            np.asarray(st.codec.scale)[0],
+            np.float32([28.0, 28.0, space]) / np.float32(32767.0),
+        )
+        # Migration is decided before the forces, so both codecs share the
+        # slot layout and per-slot positions compare directly.
+        finals[codec] = np.asarray(st.pool.position)[np.asarray(st.pool.alive)]
+    assert finals["int16"].shape == finals["none"].shape
+    err = float(np.abs(finals["int16"] - finals["none"]).max())
+    print(f"int16 vs f32 halo wire, max position error {err:.2e}")
+    assert err < 2e-3, err
+    print("codec full depth OK")
+
+
 if __name__ == "__main__":
     which = sys.argv[1] if len(sys.argv) > 1 else "all"
     table = {
@@ -1055,6 +1096,7 @@ if __name__ == "__main__":
         "overlap_smoke8": scenario_overlap_smoke8,
         "diffusion_edge_parity": scenario_diffusion_edge_parity,
         "diffusion_uneven_parity": scenario_diffusion_uneven_parity,
+        "codec_full_depth": scenario_codec_full_depth,
     }
     if which == "all":
         for name, fn in table.items():

@@ -46,7 +46,7 @@ def body(x, e):
     true = x * jnp.float32((1+2+3+4+5+6+7+8) / 8.0)
     return out, ne, true
 
-# jax-version-compat shard_map (check_vma/check_rep gated automatically)
+# the repo's shard_map wrapper (varying-axes check off)
 from repro.core.distributed import shard_map
 fn = jax.jit(shard_map(body, mesh=mesh, in_specs=(P(), P()),
                        out_specs=(P(), P(), P())))

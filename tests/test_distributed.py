@@ -192,6 +192,14 @@ def test_distributed_diffusion_uneven_resolution():
 
 
 @pytest.mark.subprocess
+def test_halo_codec_spans_full_depth():
+    """Regression: the int16 halo codec's scale spanned only the decomposed
+    extent, so on a cube split 2x2 ghost z saturated at extent + 2·halo."""
+    out = _run("codec_full_depth")
+    assert "codec full depth OK" in out
+
+
+@pytest.mark.subprocess
 def test_distributed_honors_engine_bounds():
     """Regression: the distributed step ignored EngineConfig.min_bound/
     max_bound/boundary for non-decomposed dims (hardcoded closed [0, depth])."""
@@ -202,6 +210,23 @@ def test_distributed_honors_engine_bounds():
 # ---------------------------------------------------------------------------
 # In-process unit tests (no devices needed): the sort-free packing primitives.
 # ---------------------------------------------------------------------------
+
+
+def test_codec_span_is_per_dim():
+    """The halo codec spans the halo-extended extent on decomposed dims and
+    the depth on the rest, so a deep domain widens only z's quantum."""
+    from repro.core.distributed import DomainConfig
+
+    two = DomainConfig(
+        mesh_axes=("data", "model"), axis_sizes=(2, 2), extent=24.0,
+        halo_width=2.0, halo_capacity=8, migrate_capacity=8, depth=48.0,
+    )
+    assert two.codec_span == (28.0, 28.0, 48.0)
+    three = DomainConfig(
+        mesh_axes=("data", "model", "pod"), axis_sizes=(2, 2, 2),
+        extent=24.0, halo_width=2.0, halo_capacity=8, migrate_capacity=8,
+    )
+    assert three.codec_span == (28.0, 28.0, 28.0)
 
 
 def test_select_matches_stable_argsort_reference():

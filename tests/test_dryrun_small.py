@@ -34,7 +34,7 @@ for shape_name in ("train_4k", "decode_32k"):
     shape = SHAPES[shape_name]
     lowered = dr.lower_cell("gemma-7b", shape_name, mesh, cfg=cfg)
     compiled = lowered.compile()
-    cost = dr.cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     assert cost.get("flops", 0) > 0
     hlo = dr._strip_done_ops(compiled.as_text())
     coll = dr.collective_bytes_from_hlo(hlo)

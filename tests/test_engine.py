@@ -6,6 +6,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import (
     INFECTED,
@@ -198,3 +199,22 @@ def test_fused_fallback_builds_candidates_once(monkeypatch):
     )
     simulation_step(config, init_state(pool, seed=0))
     assert calls["n"] == 1
+
+
+@pytest.mark.parametrize(
+    "field,build",
+    [
+        ("force_impl", lambda spec: EngineConfig(spec=spec, force_impl="fusd")),
+        ("diffusion_impl",
+         lambda spec: EngineConfig(spec=spec, diffusion_impl="palas")),
+        ("tile_order", lambda spec: EngineConfig(spec=spec, tile_order="z")),
+        ("rank_impl",
+         lambda spec: dataclasses.replace(spec, rank_impl="argsort")),
+    ],
+)
+def test_unknown_impl_strings_rejected(field, build):
+    """A typo'd impl must fail at construction, naming the valid choices —
+    never quietly run the reference path."""
+    spec = spec_for_space(0.0, 10.0, 2.0, max_per_cell=4)
+    with pytest.raises(ValueError, match=f"unknown {field}.*expected one of"):
+        build(spec)

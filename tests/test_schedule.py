@@ -115,7 +115,7 @@ def _frozen_reference_step(config, state):
                 active_capacity=config.active_capacity, impl=config.force_impl,
                 neighbors=neighbors,
                 fused_fallback=config.fused_overflow_fallback,
-                interpret=config.kernel_interpret, tile=config.force_tile,
+                tile=config.force_tile,
             )
 
         def _zero(_):
