@@ -142,14 +142,16 @@ def canonicalize_attr(name: str, value: Any, n: int) -> Array:
     """Validate/broadcast one per-agent attribute to ``n`` leading rows.
 
     Scalars broadcast to ``(n,)`` (dtype inferred by jnp: python floats →
-    f32, ints → i32, bools → bool); arrays must already carry ``n`` rows.
+    f32, ints → i32, bools → bool) and strongly typed, as the step's output
+    is: a weakly typed attribute would make the next jitted call of a
+    chunked run trace again.  Arrays must already carry ``n`` rows.
     Used by :class:`~repro.core.api.Simulation` so a registration error
     surfaces at declaration time with the attribute's name, not as a shape
     mismatch deep inside ``make_pool``/jit.
     """
     arr = jnp.asarray(value)
     if arr.ndim == 0:
-        return jnp.full((n,), arr)
+        return jnp.full((n,), arr, dtype=arr.dtype)
     if arr.shape[0] != n:
         raise ValueError(
             f"attr {name!r}: leading dim {arr.shape[0]} != {n} agents in this "

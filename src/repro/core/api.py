@@ -44,6 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import diffusion as dgrid
+from .. import spans
 from ..checkpoint import checkpoint as _ckpt
 from .agents import (
     attr_signature,
@@ -931,12 +932,14 @@ class BuiltSimulation:
 
     def _execute(self, n_steps: int, state, jit: bool):
         state = self.state if state is None else state
-        start = int(jax.device_get(state.step))
+        with jax.profiler.TraceAnnotation(spans.READ_STEP):
+            start = int(jax.device_get(state.step))
         triples = self._obs_triples()
         if jit:
-            final, ys = self._jitted(
-                state, n_steps=n_steps, observables=triples or None
-            )
+            with jax.profiler.TraceAnnotation(spans.LAUNCH):
+                final, ys = self._jitted(
+                    state, n_steps=n_steps, observables=triples or None
+                )
         else:
             final, ys = _engine.run(
                 self.config, state, n_steps,
@@ -1109,9 +1112,11 @@ class DistributedSimulation:
         rows: Dict[str, List[Array]] = {o.name: [] for o in live}
         # One host sync for the counter; it advances by exactly 1 per step,
         # so the loop stays asynchronous (no per-step device_get).
-        start = int(np.asarray(jax.device_get(state.step)).ravel()[0])
+        with jax.profiler.TraceAnnotation(spans.READ_STEP):
+            start = int(np.asarray(jax.device_get(state.step)).ravel()[0])
         for i in range(n_steps):
-            state = self.step(state)
+            with jax.profiler.TraceAnnotation(spans.LAUNCH):
+                state = self.step(state)
             for o in live:
                 if (start + i) % o.frequency == 0:
                     rows[o.name].append(o.fn(state))

@@ -621,8 +621,7 @@ def migrate_op(dcfg: DomainConfig) -> Operation:
     """§6.2.1 repartitioning as a pre standalone op."""
 
     def fn(ctx: OpContext, state: DistState) -> DistState:
-        with jax.named_scope("migrate"):
-            pool, ovf = migrate(dcfg, state.pool)
+        pool, ovf = migrate(dcfg, state.pool)
         # Seal the migrated positions: the frame-rebase arithmetic
         # (``x ± extent``) is cheap enough for the backend to duplicate
         # into consumer fusions, where it may re-round differently per
@@ -646,10 +645,9 @@ def halo_exchange_op(dcfg: DomainConfig) -> Operation:
     into the state."""
 
     def fn(ctx: OpContext, state: DistState) -> DistState:
-        with jax.named_scope("halo_exchange"):
-            g_pos, g_rad, g_kind, g_alive, codec, ovf, wire = halo_exchange(
-                dcfg, state.pool, state.codec
-            )
+        g_pos, g_rad, g_kind, g_alive, codec, ovf, wire = halo_exchange(
+            dcfg, state.pool, state.codec
+        )
         ctx.extras["halo_sources"] = (g_pos, g_rad, g_kind, g_alive)
         c = state.pool.capacity
         ghost = GhostFrame(
@@ -775,11 +773,10 @@ def interior_env_build_op(dcfg: DomainConfig, ecfg: EngineConfig) -> Operation:
 
     def fn(ctx: OpContext, state: DistState) -> DistState:
         pool = state.pool
-        with jax.named_scope("interior_env_build"):
-            index = build_index_arrays(ecfg.spec, pool.position, pool.alive)
-            interior, shell = interior_shell_masks(
-                dcfg, ecfg.spec, pool.position, pool.alive
-            )
+        index = build_index_arrays(ecfg.spec, pool.position, pool.alive)
+        interior, shell = interior_shell_masks(
+            dcfg, ecfg.spec, pool.position, pool.alive
+        )
         ctx.extras["interior_index"] = index
         ctx.extras["interior_neighbors"] = NeighborContext.for_pool(
             ecfg.spec, index, pool
@@ -807,7 +804,6 @@ def interior_forces_op(dcfg: DomainConfig, ecfg: EngineConfig) -> Operation:
             index=ctx.extras["interior_index"],
             neighbors=ctx.extras["interior_neighbors"],
             row_mask=ctx.extras["interior_mask"],
-            scope="interior_forces",
         )
         return state
 
@@ -825,7 +821,6 @@ def shell_forces_op(dcfg: DomainConfig, ecfg: EngineConfig) -> Operation:
         shell_force = force_pass(
             ecfg, ctx, state,
             row_mask=ctx.extras["shell_mask"],
-            scope="shell_forces",
         )
         force = jnp.where(
             ctx.extras["interior_mask"][:, None],

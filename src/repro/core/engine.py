@@ -30,6 +30,7 @@ from typing import Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import spans
 from . import diffusion as dgrid
 from .agents import AgentPool
 from .behaviors import Behavior
@@ -123,6 +124,7 @@ def simulation_step(config: EngineConfig, state: SimulationState) -> SimulationS
     return Scheduler.default(config).step(state)
 
 
+@functools.partial(jax.profiler.annotate_function, name=spans.TRACE_SCHEDULE)
 def run(
     config: EngineConfig,
     state: SimulationState,
@@ -148,6 +150,10 @@ def run(
     start step, slices them off.  ``collect`` and ``observables`` are
     mutually exclusive.  ``scheduler`` overrides the default operation
     schedule (custom ops, DESIGN.md §5); returns ``(final_state, outs)``.
+
+    Runs under the host span ``trace_schedule`` (:mod:`repro.spans`).  Under
+    ``jax.jit`` this body runs only when JAX traces, so in a profiler trace
+    each such span is one (re)trace of the schedule.
     """
     if collect is not None and observables:
         raise ValueError("pass either collect= or observables=, not both")

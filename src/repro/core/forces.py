@@ -28,6 +28,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from .. import spans
 from .agents import AgentPool, compact_indices
 from .grid import _NEIGHBOR_OFFSETS, GridIndex, GridSpec, neighbor_cell_ids
 from .neighbors import NeighborContext
@@ -361,11 +362,12 @@ def mechanical_forces(
             else:
                 fused = morton_eval
         if fused_fallback:
-            dense = lambda: jax.lax.cond(
-                index.overflowed,
-                lambda: dense_eval(cache=False),
-                fused,
-            )
+
+            def fallback():
+                with jax.named_scope(spans.DENSE_FALLBACK):
+                    return dense_eval(cache=False)
+
+            dense = lambda: jax.lax.cond(index.overflowed, fallback, fused)
         else:
             dense = fused
     else:

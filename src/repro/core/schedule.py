@@ -185,16 +185,22 @@ class Operation:
 
 
 def run_op(op: Operation, ctx: OpContext, state):
-    """Execute one op with its frequency gate applied."""
+    """Execute one op with its frequency gate applied, under a named scope
+    of the op's name: every instruction the op lowers to, gate included,
+    carries the name in its ``op_name`` metadata, so a device trace
+    attributes its time op by op."""
     if op.frequency == 0:
         return state
-    if op.frequency == 1:
-        return op.fn(ctx, state)
-    fires = (ctx.step % op.frequency) == 0
-    if op.gate == "cond":
-        return jax.lax.cond(fires, lambda s: op.fn(ctx, s), lambda s: s, state)
-    new = op.fn(ctx, state)
-    return jax.tree.map(lambda a, b: jnp.where(fires, a, b), new, state)
+    with jax.named_scope(op.name):
+        if op.frequency == 1:
+            return op.fn(ctx, state)
+        fires = (ctx.step % op.frequency) == 0
+        if op.gate == "cond":
+            return jax.lax.cond(
+                fires, lambda s: op.fn(ctx, s), lambda s: s, state
+            )
+        new = op.fn(ctx, state)
+        return jax.tree.map(lambda a, b: jnp.where(fires, a, b), new, state)
 
 
 # ---------------------------------------------------------------------------
@@ -388,16 +394,17 @@ def behaviors_op(config) -> Operation:
 
 
 def force_pass(config, ctx: OpContext, state, *, index=None, neighbors=None,
-               row_mask=None, scope: str = "forces") -> Array:
+               row_mask=None) -> Array:
     """One ``mechanical_forces`` dispatch with the config's knobs applied.
 
     The single anchoring point for every force evaluation in either engine:
     the default ``forces`` op runs it once over the step's index/context;
     the distributed overlapped schedule runs it twice — an interior pass
     over a local-only index and a shell pass over the ghost-extended one —
-    with complementary ``row_mask``s (DESIGN.md §4).  ``scope`` names the
-    pass in lowered-HLO op metadata so the overlap benchmark can locate the
-    interior pass and the halo collective in the scheduled module text.
+    with complementary ``row_mask``s (DESIGN.md §4).  The calling op's
+    scope (:func:`run_op`) names the pass in lowered-HLO op metadata, so the
+    overlap report can locate each pass's fence (``/<op name>/cond``) and
+    the halo collective in the scheduled module text.
 
     The dispatch runs inside a ``lax.cond`` on a *runtime* predicate
     (``any(alive)``) — a **fusion fence**.  XLA compiles a conditional
@@ -414,34 +421,33 @@ def force_pass(config, ctx: OpContext, state, *, index=None, neighbors=None,
     is zero.  The result still passes through :func:`seal` to pin one
     rounding on the merge/displacement consumers outside the fence.
     """
-    with jax.named_scope(scope):
-        pool = state.pool
-        use_index = ctx.index if index is None else index
-        use_neighbors = ctx.neighbors if neighbors is None else neighbors
+    pool = state.pool
+    use_index = ctx.index if index is None else index
+    use_neighbors = ctx.neighbors if neighbors is None else neighbors
 
-        def _run(_):
-            return mechanical_forces(
-                config.spec,
-                use_index,
-                pool,
-                config.force_params,
-                active_capacity=config.active_capacity,
-                impl=config.force_impl,
-                neighbors=use_neighbors,
-                fused_fallback=config.fused_overflow_fallback,
-                tile=config.force_tile,
-                tile_order=config.tile_order,
-                morton_block=config.morton_block,
-                morton_window=config.morton_window,
-                morton_fallback=config.morton_window_fallback,
-                row_mask=row_mask,
-            )
+    def _run(_):
+        return mechanical_forces(
+            config.spec,
+            use_index,
+            pool,
+            config.force_params,
+            active_capacity=config.active_capacity,
+            impl=config.force_impl,
+            neighbors=use_neighbors,
+            fused_fallback=config.fused_overflow_fallback,
+            tile=config.force_tile,
+            tile_order=config.tile_order,
+            morton_block=config.morton_block,
+            morton_window=config.morton_window,
+            morton_fallback=config.morton_window_fallback,
+            row_mask=row_mask,
+        )
 
-        def _zero(_):
-            return jnp.zeros((pool.capacity, 3), jnp.float32)
+    def _zero(_):
+        return jnp.zeros((pool.capacity, 3), jnp.float32)
 
-        force = jax.lax.cond(jnp.any(pool.alive), _run, _zero, None)
-        return seal(force)
+    force = jax.lax.cond(jnp.any(pool.alive), _run, _zero, None)
+    return seal(force)
 
 
 def apply_force(pool, force: Array, dt: float):

@@ -202,6 +202,7 @@ def cell_list_force_planar(
         out_specs=pl.BlockSpec((3, 1, nz, m), lambda i, o: (0, i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((3, n_cols, nz, m), jnp.float32),
         interpret=interpret_default(interpret),
+        name="cell_list_force_planar",
     )(cpos, crad, cval, cpos, crad, cval)
 
 
@@ -345,4 +346,5 @@ def cell_window_force_planar(
         out_specs=pl.BlockSpec((4, t), qry_idx),
         out_shape=jax.ShapeDtypeStruct((4, c), jnp.float32),
         interpret=interpret_default(interpret),
+        name="cell_window_force_planar",
     )(ppos, pcid, ppos, pcid)

@@ -22,6 +22,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import spans
+
 from . import kernel as _kernel
 from .ref import cell_list_force_ref
 
@@ -89,19 +91,26 @@ def cell_list_force(
             num_out=num_out,
         )
 
-    cpos, crad, cval = _cell_major_planar(position, radius, cell_list, dims)
-    slot_force = _kernel.cell_list_force_planar(
-        cpos, crad, cval, dims, k=k, gamma=gamma, interpret=interpret
-    )                                                       # (3, n_cols, nz, M)
+    # Each stage runs under a named scope of its own (`repro.spans`), so a
+    # device trace splits the force pass into gather, kernel and scatter.
+    with jax.named_scope(spans.CELL_GATHER):
+        cpos, crad, cval = _cell_major_planar(
+            position, radius, cell_list, dims
+        )
+    with jax.named_scope(spans.CELL_KERNEL):
+        slot_force = _kernel.cell_list_force_planar(
+            cpos, crad, cval, dims, k=k, gamma=gamma, interpret=interpret
+        )                                                   # (3, n_cols, nz, M)
 
     # Scatter per-slot forces back to agent order.  Empty slots carry exactly
     # zero (masked in-kernel); their sentinel index S — and any ghost row
     # ≥ num_out — is out of range and drops.
-    slot_force = slot_force.reshape(3, n_cells * m).T       # (n_cells·M, 3)
-    slots = cell_list.reshape(-1)
-    return jnp.zeros((out_n, 3), jnp.float32).at[slots].add(
-        slot_force, mode="drop"
-    )
+    with jax.named_scope(spans.CELL_SCATTER):
+        slot_force = slot_force.reshape(3, n_cells * m).T   # (n_cells·M, 3)
+        slots = cell_list.reshape(-1)
+        return jnp.zeros((out_n, 3), jnp.float32).at[slots].add(
+            slot_force, mode="drop"
+        )
 
 
 def window_defaults(c: int, block: int | None, window: int | None
@@ -163,8 +172,9 @@ def cell_window_force(
         ppos = jnp.pad(ppos, [(0, 0), (0, pad)])
         pcid = jnp.pad(pcid, [(0, pad)], constant_values=n_cells)
 
-    out = _kernel.cell_window_force_planar(
-        ppos, pcid[None], dims, k=k, gamma=gamma,
-        block=bw, half_window=h, interpret=interpret,
-    )
+    with jax.named_scope(spans.CELL_KERNEL):
+        out = _kernel.cell_window_force_planar(
+            ppos, pcid[None], dims, k=k, gamma=gamma,
+            block=bw, half_window=h, interpret=interpret,
+        )
     return out[:3, :c].T

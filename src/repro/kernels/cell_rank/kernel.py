@@ -95,4 +95,5 @@ def cell_rank_tiled(
         out_shape=jax.ShapeDtypeStruct((l, t), jnp.int32),
         scratch_shapes=[pltpu.VMEM((1, hist_width), jnp.int32)],
         interpret=interpret_default(interpret),
+        name="cell_rank_tiled",
     )(cid_cols)

@@ -67,5 +67,6 @@ def diffusion_step_pallas(
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((nxp, ny, nz), u.dtype),
         interpret=interpret_default(interpret),
+        name="diffusion_step_pallas",
     )(*args)
     return out[:nx]

@@ -119,4 +119,5 @@ def pairwise_force_planar(
         out_specs=pl.BlockSpec((3, tile_n), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((3, n), jnp.float32),
         interpret=interpret_default(interpret),
+        name="pairwise_force_planar",
     )(pos, rad, cand_pos, cand_rad, cand_mask)
