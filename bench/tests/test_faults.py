@@ -16,6 +16,8 @@ import run as harness
 
 with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as f:
     CELLS = [w["name"] for w in json.load(f)["workloads"]]
+SUBSTANCE_CELLS = [c for c in CELLS
+                   if harness.Cell.load(c).cfg.get("substances")]
 AGENTS = 2048
 
 
@@ -57,6 +59,29 @@ def overflowed(before, after):
     return dataclasses.replace(after, health=health)
 
 
+def dose_at_chunk_start(cfg):
+    """The step's exposure dose is sampled at the chunk-start positions,
+    not at the end-of-step ones (the same grids, the same agents)."""
+    from repro.core import concentration_at
+
+    def planted(before, after):
+        pool = after.pool
+        start = by_tag_from(before, after, lambda p: p.position)
+
+        def dose(pos):
+            own = jnp.zeros(pool.kind.shape, jnp.float32)
+            for sub in cfg["substances"]:
+                c = concentration_at(after.grids[sub["name"]], pos)
+                own = jnp.where(pool.kind == sub["kind"], c, own)
+            return jnp.where(pool.alive, own * cfg["dt"], 0.0)
+
+        exposure = pool.get("exposure") - dose(pool.position) + dose(start)
+        return dataclasses.replace(after,
+                                   pool=pool.set_attr("exposure", exposure))
+
+    return planted
+
+
 def only_last(fault):
     """The fault in the window's last chunk alone: the first compared chunk
     stays sound."""
@@ -86,6 +111,15 @@ def test_sound_run_is_correct(cell):
 @pytest.mark.parametrize("cell", CELLS)
 def test_fault_is_not_correct(cell, fault):
     assert not all(run(cell, fault).values())
+
+
+@pytest.mark.parametrize("cell", SUBSTANCE_CELLS)
+def test_dose_at_chunk_start_positions_is_not_correct(cell):
+    """The check doses the reference at the program's end-of-step
+    positions; a program that doses where its agents started still fails
+    (chemotaxis alone moves an agent 0.75 a step against 5-unit voxels)."""
+    checks = run(cell, dose_at_chunk_start(harness.Cell.load(cell).cfg))
+    assert not checks["exposure_gap"], checks
 
 
 @pytest.mark.parametrize("cell", CELLS)
