@@ -87,7 +87,8 @@ def _stage_fns(spec, params):
 
     def fused(pool, index):
         return cf_ops.cell_list_force(
-            pool.position, pool.radius(), index.cell_list, spec.dims,
+            pool.position, pool.radius(), index.cell_list,
+            index.slot_of_agent, spec.dims,
             k=params.repulsion_k, gamma=params.attraction_gamma,
         )
 

@@ -107,4 +107,5 @@ def argsort_build_index(spec, position, alive):
         cell_list=cell_list,
         cell_count=cell_count,
         overflowed=jnp.any(cell_count > m),
+        slot_of_agent=flat_idx,
     )

@@ -255,8 +255,9 @@ def mechanical_forces(
     all impls gather pair data from those sources; their local rows are
     refreshed to the pool's current (post-behavior) state, exactly what the
     single-node engine sees, while halo rows keep the exchange-time
-    snapshot.  The fused kernel's slot forces then scatter back to *local*
-    rows only (ghost slots drop) so the result stays (C, 3).
+    snapshot.  The fused kernel's slot forces are then gathered back for
+    *local* rows only (ghost slots are never read) so the result stays
+    (C, 3).
 
     ``fused_fallback`` guards the fused path's cell-list truncation: when
     any cell overflowed ``max_per_cell`` a ``lax.cond`` re-evaluates through
@@ -343,8 +344,8 @@ def mechanical_forces(
         from repro.kernels.cell_force import ops as cf_ops
 
         fused = lambda: cf_ops.cell_list_force(
-            src_pos, src_rad, index.cell_list, spec.dims,
-            k=params.repulsion_k, gamma=params.attraction_gamma,
+            src_pos, src_rad, index.cell_list, index.slot_of_agent,
+            spec.dims, k=params.repulsion_k, gamma=params.attraction_gamma,
             num_out=c,
         )
         if tile_order == "morton" and src_pos is pool.position:

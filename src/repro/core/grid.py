@@ -77,12 +77,16 @@ class GridIndex:
     cell_list:     (n_cells, M) int32 — agent index per slot, C where empty.
     cell_count:    (n_cells,) int32 — #agents per cell (may exceed M; overflow).
     overflowed:    ()   bool — any cell exceeded max_per_cell.
+    slot_of_agent: (C,)  int32 — each agent's flat slot in
+                   ``cell_list.reshape(-1)``; n_cells·M for dead agents and
+                   agents dropped from an overflowing cell.
     """
 
     cell_of_agent: Array
     cell_list: Array
     cell_count: Array
     overflowed: Array
+    slot_of_agent: Array
 
 
 def cell_coords(spec: GridSpec, position: Array) -> Array:
@@ -214,7 +218,9 @@ def build_index_arrays(
       2. rank of each agent within its cell, via the tiled-histogram pass of
          `repro.kernels.cell_rank` (per-tile per-cell counts → exclusive
          scan over tiles → intra-tile ranks; impl per ``spec.rank_impl``);
-      3. scatter agent indices into ``cell_list[cell, rank]`` (O(C)).
+      3. scatter agent indices into ``cell_list[cell, rank]`` (O(C)); the
+         flat slot ``cell·M + rank`` is kept as ``slot_of_agent``, the
+         inverse map the fused force pass gathers through.
 
     ``rank_tile`` overrides the ≈√n_cells rank tile (tests keep
     interpret-mode grids coarse with it).
@@ -263,6 +269,7 @@ def build_index_arrays(
         cell_list=cell_list,
         cell_count=cell_count,
         overflowed=overflowed,
+        slot_of_agent=flat_idx,
     )
 
 

@@ -39,7 +39,7 @@ Distributed adoption (§6.2.1, DESIGN.md §4): the kernel is oblivious to the
 local/ghost split — the distributed engine builds the cell list over its
 halo-*extended* grid (halo agents land in boundary cells, so the column
 decomposition and the 9-offset shift arithmetic apply unchanged) and
-restricts the scatter-back in ops.py to local rows (``num_out``).  Ghost
+gathers only local rows back in ops.py (``num_out``).  Ghost
 slots cost kernel FLOPs but no extra HBM layout: they are ordinary occupied
 slots of boundary columns.
 """
@@ -308,7 +308,7 @@ def cell_window_force_planar(
     grid is ``(C/T, 2·half_window + 1)``: program (i, w) folds window block
     ``i + w − half_window`` into query tile ``i`` — every load is a
     contiguous DMA of consecutive agents (near-zero gather cost), vs the
-    cell-major path's O(n_cells·M) slot gather/scatter.
+    cell-major path's O(n_cells·M) slot gather.
 
     Exactness is by masking, not by layout: pairs outside the 27-box
     adjacency (decoded from cell ids) contribute nothing, so the kernel is

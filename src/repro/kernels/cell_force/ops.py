@@ -2,9 +2,10 @@
 
 ``cell_list_force`` consumes the grid's ``cell_list`` *directly*: the only
 XLA-side work is the O(n_cells·M) gather into the cell-major planar layout
-and the O(n_cells·M) scatter of per-slot forces back to agent order.  The
-``(N, 27·M)`` candidate tensor, its boolean mask, and the ``(N, K, 3)``
-candidate-position gather of the dense path never exist.
+and an O(N) gather of per-slot forces back to agent order, through the
+grid build's own inverse map ``slot_of_agent``.  The ``(N, 27·M)``
+candidate tensor, its boolean mask, and the ``(N, K, 3)`` candidate-position
+gather of the dense path never exist.
 
 Semantics match the candidate path exactly when no cell overflowed: the pair
 set is "all agents in the 27-box neighborhood, minus self".  Agents dropped
@@ -64,6 +65,7 @@ def cell_list_force(
     position: Array,    # (S, 3) f32 — all indexed agents (pool, or pool+ghosts)
     radius: Array,      # (S,) f32
     cell_list: Array,   # (n_cells, M) int32, empty slots = S
+    slot_of_agent: Array,  # (S,) int32 flat slot per row, n_cells·M = none
     dims: tuple,        # (nx, ny, nz) static — n_cells must equal nx·ny·nz
     k: float = 2.0,
     gamma: float = 1.0,
@@ -73,10 +75,15 @@ def cell_list_force(
 ) -> Array:
     """Net Eq-4.1 force per agent, (num_out, 3), straight from the cell list.
 
-    ``num_out`` (default: all S rows) restricts the scatter-back to the first
+    ``slot_of_agent`` is :attr:`repro.core.grid.GridIndex.slot_of_agent`
+    of the same build: each row's flat slot in ``cell_list``, so the result
+    is one gather of the kernel's per-slot forces.  A row holds at most one
+    slot, so this equals scatter-adding every slot back (0 + x == x).
+
+    ``num_out`` (default: all S rows) restricts the result to the first
     ``num_out`` source rows — the distributed engine passes its local pool
     capacity so forces land on local agents only while ghost (halo) slots'
-    contributions are computed in-kernel but dropped by the scatter (§6.2.1:
+    contributions are computed in-kernel but never gathered (§6.2.1:
     ghosts are read-only copies; their owners integrate them remotely).
     """
     nx, ny, nz = dims
@@ -92,7 +99,7 @@ def cell_list_force(
         )
 
     # Each stage runs under a named scope of its own (`repro.spans`), so a
-    # device trace splits the force pass into gather, kernel and scatter.
+    # device trace splits the force pass into its three stages.
     with jax.named_scope(spans.CELL_GATHER):
         cpos, crad, cval = _cell_major_planar(
             position, radius, cell_list, dims
@@ -102,15 +109,14 @@ def cell_list_force(
             cpos, crad, cval, dims, k=k, gamma=gamma, interpret=interpret
         )                                                   # (3, n_cols, nz, M)
 
-    # Scatter per-slot forces back to agent order.  Empty slots carry exactly
-    # zero (masked in-kernel); their sentinel index S — and any ghost row
-    # ≥ num_out — is out of range and drops.
+    # Gather each row's force from its own slot, along the planes' minor
+    # axis.  Dead and dropped rows hold the sentinel n_cells·M, out of range,
+    # and read 0; ghost rows ≥ num_out are never gathered.
     with jax.named_scope(spans.CELL_SCATTER):
-        slot_force = slot_force.reshape(3, n_cells * m).T   # (n_cells·M, 3)
-        slots = cell_list.reshape(-1)
-        return jnp.zeros((out_n, 3), jnp.float32).at[slots].add(
-            slot_force, mode="drop"
-        )
+        return jnp.take(
+            slot_force.reshape(3, n_cells * m), slot_of_agent[:out_n], axis=1,
+            mode="fill", fill_value=0.0,
+        ).T                                                 # (out_n, 3)
 
 
 def window_defaults(c: int, block: int | None, window: int | None

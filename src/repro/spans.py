@@ -14,7 +14,7 @@ host was doing in it.
 # Force-layer stages (device scopes).
 CELL_GATHER = "cell_gather"        # pool arrays -> cell-major planar layout
 CELL_KERNEL = "cell_kernel"        # the Pallas force kernel
-CELL_SCATTER = "cell_scatter"      # per-slot forces -> agent order
+CELL_SCATTER = "cell_scatter"      # per-slot forces -> agent order (a gather by slot)
 DENSE_FALLBACK = "dense_fallback"  # candidate path taken on a cell overflow
 STAGES = (CELL_GATHER, CELL_KERNEL, CELL_SCATTER, DENSE_FALLBACK)
 

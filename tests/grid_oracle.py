@@ -78,4 +78,5 @@ def build_index_arrays_argsort(
         cell_list=cell_list,
         cell_count=cell_count,
         overflowed=overflowed,
+        slot_of_agent=flat_idx,
     )

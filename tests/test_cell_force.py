@@ -18,7 +18,8 @@ from repro.core import (
     spec_for_space,
 )
 from repro.core.forces import update_static_flags, update_static_flags_celllist
-from repro.core.grid import candidate_neighbors
+from repro.core.grid import build_index_arrays, candidate_neighbors
+from repro.kernels.cell_force import kernel as cf_kernel
 from repro.kernels.cell_force import ops as cf_ops
 
 
@@ -50,10 +51,83 @@ def test_kernel_matches_oracle(n, cap, space, radius, m):
     spec = spec_for_space(0.0, space, radius, max_per_cell=m)
     index = build_index(spec, pool)
     assert not bool(index.overflowed)
-    args = (pool.position, pool.radius(), index.cell_list, spec.dims)
+    args = (pool.position, pool.radius(), index.cell_list,
+            index.slot_of_agent, spec.dims)
     ref = cf_ops.cell_list_force(*args, impl="reference")
     pal = cf_ops.cell_list_force(*args, impl="pallas")
     np.testing.assert_allclose(np.asarray(pal), np.asarray(ref), atol=1e-5)
+
+
+def _scatter_add_oracle(position, radius, cell_list, dims, num_out):
+    """The slot forces' return to agent order as a scatter-add over every
+    slot of the cell list, out-of-range (empty or ghost) slots dropped."""
+    n_cells, m = cell_list.shape
+    cpos, crad, cval = cf_ops._cell_major_planar(
+        position, radius, cell_list, dims
+    )
+    slot_force = cf_kernel.cell_list_force_planar(cpos, crad, cval, dims)
+    return jnp.zeros((num_out, 3), jnp.float32).at[cell_list.reshape(-1)].add(
+        slot_force.reshape(3, n_cells * m).T, mode="drop"
+    )
+
+
+def _clustered_pool(rng):
+    """Ten agents packed into one corner cell (it overflows at M = 4),
+    forty spread out."""
+    pos = np.concatenate(
+        [rng.uniform(1.0, 2.0, (10, 3)), rng.uniform(0, 30.0, (40, 3))]
+    ).astype(np.float32)
+    return make_pool(64, jnp.asarray(pos), diameter=3.0)
+
+
+@pytest.mark.parametrize("case", ["generic", "dead", "overflow", "ghost"])
+def test_slot_gather_matches_scatter_add(case):
+    """Gathering each agent's force through ``slot_of_agent`` is bit for
+    bit the scatter-add of every slot (each agent holds at most one slot):
+    dead and dropped agents read 0, and ghost rows past ``num_out`` never
+    reach the output."""
+    rng = np.random.default_rng(23)
+    num_out = None
+    if case == "overflow":
+        pool = _clustered_pool(rng)
+        spec = spec_for_space(0.0, 30.0, 3.0, max_per_cell=4)
+    else:
+        pool = _random_pool(
+            rng, 120, 160, 40.0, dead_frac=0.5 if case == "dead" else 0.0
+        )
+        spec = spec_for_space(0.0, 40.0, 5.0, max_per_cell=16)
+    position, radius, alive = pool.position, pool.radius(), pool.alive
+    if case == "ghost":
+        # Local rows first, then ghosts, as in the distributed engine's
+        # ghost-extended build; some ghost rows dead.
+        ghosts = _random_pool(rng, 40, 48, 40.0, dead_frac=0.25)
+        num_out = pool.capacity
+        position = jnp.concatenate([position, ghosts.position])
+        radius = jnp.concatenate([radius, ghosts.radius()])
+        alive = jnp.concatenate([alive, ghosts.alive])
+    index = build_index_arrays(spec, position, alive)
+    out_n = position.shape[0] if num_out is None else num_out
+    args = (position, radius, index.cell_list, index.slot_of_agent, spec.dims)
+
+    got = cf_ops.cell_list_force(*args, impl="pallas", num_out=num_out)
+    want = _scatter_add_oracle(
+        position, radius, index.cell_list, spec.dims, out_n
+    )
+    assert got.shape == (out_n, 3)
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+    ref = cf_ops.cell_list_force(*args, impl="reference", num_out=num_out)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-5)
+
+    n_cells, m = index.cell_list.shape
+    unlisted = np.asarray(index.slot_of_agent[:out_n]) == n_cells * m
+    assert np.all(np.asarray(got)[unlisted] == 0.0)
+    if case == "overflow":
+        assert bool(index.overflowed)
+        assert np.any(unlisted & np.asarray(alive))
+    else:
+        assert not bool(index.overflowed)
+    if case in ("dead", "ghost"):
+        assert np.any(~np.asarray(alive[:out_n]))
 
 
 def test_fused_matches_reference_forces():
@@ -74,11 +148,7 @@ def test_fused_matches_reference_forces():
 def test_fused_overflow_falls_back_to_reference():
     """An overflowing cell would truncate pair forces; the lax.cond fallback
     must reproduce the dense path exactly."""
-    rng = np.random.default_rng(1)
-    pos = np.concatenate(
-        [rng.uniform(1.0, 2.0, (10, 3)), rng.uniform(0, 30.0, (40, 3))]
-    ).astype(np.float32)
-    pool = make_pool(64, jnp.asarray(pos), diameter=3.0)
+    pool = _clustered_pool(np.random.default_rng(1))
     spec = spec_for_space(0.0, 30.0, 3.0, max_per_cell=4)
     index = build_index(spec, pool)
     assert bool(index.overflowed)
@@ -121,8 +191,8 @@ def test_window_kernel_matches_oracle_full_window(n, cap, space, radius, m, bloc
     index = build_index(spec, pool)
     assert not bool(index.overflowed)
     ref = cf_ops.cell_list_force(
-        pool.position, pool.radius(), index.cell_list, spec.dims,
-        impl="reference",
+        pool.position, pool.radius(), index.cell_list, index.slot_of_agent,
+        spec.dims, impl="reference",
     )
     win = cf_ops.cell_window_force(
         pool.position, pool.radius(), index.cell_of_agent, spec.dims,
@@ -144,8 +214,8 @@ def test_window_kernel_sorted_narrow_window():
     index = build_index(spec, pool, assume_sorted=True)
     assert bool(_morton_window_ok(spec, index, 32, 3))
     ref = cf_ops.cell_list_force(
-        pool.position, pool.radius(), index.cell_list, spec.dims,
-        impl="reference",
+        pool.position, pool.radius(), index.cell_list, index.slot_of_agent,
+        spec.dims, impl="reference",
     )
     win = cf_ops.cell_window_force(
         pool.position, pool.radius(), index.cell_of_agent, spec.dims,

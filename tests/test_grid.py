@@ -9,6 +9,7 @@ import dataclasses
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
@@ -271,6 +272,10 @@ def _assert_build_parity(spec, position, alive, tile=16):
             np.asarray(got.cell_count), np.asarray(want.cell_count),
             err_msg=f"cell_count diverged ({impl})",
         )
+        np.testing.assert_array_equal(
+            np.asarray(got.slot_of_agent), np.asarray(want.slot_of_agent),
+            err_msg=f"slot_of_agent diverged ({impl})",
+        )
         assert bool(got.overflowed) == bool(want.overflowed), impl
 
 
@@ -447,7 +452,45 @@ def test_sorted_fast_path_build_parity():
         np.testing.assert_array_equal(
             np.asarray(got.cell_count), np.asarray(want.cell_count)
         )
+        np.testing.assert_array_equal(
+            np.asarray(got.slot_of_agent), np.asarray(want.slot_of_agent)
+        )
         assert bool(got.overflowed) == bool(want.overflowed)
+
+
+@pytest.mark.parametrize("build", ["cell_rank", "assume_sorted", "oracle"])
+def test_slot_of_agent_inverts_cell_list(build):
+    """``slot_of_agent`` is each listed agent's flat slot in the cell list,
+    and n_cells·M for dead agents and agents dropped from a full cell — the
+    same from both build branches and from the argsort oracle."""
+    rng = np.random.default_rng(5)
+    spec = spec_for_space(0.0, 20.0, 4.0, max_per_cell=2)
+    pool = sort_agents(spec, _random_attr_pool(rng, 80, 96, 0.0, 20.0))
+    want = build_index_arrays_argsort(spec, pool.position, pool.alive)
+    if build == "oracle":
+        index = want
+    else:
+        index = build_index_arrays(
+            spec, pool.position, pool.alive,
+            assume_sorted=build == "assume_sorted",
+        )
+    np.testing.assert_array_equal(
+        np.asarray(index.slot_of_agent), np.asarray(want.slot_of_agent)
+    )
+
+    assert index.slot_of_agent.dtype == jnp.int32
+    slots = np.asarray(index.slot_of_agent)
+    flat = np.asarray(index.cell_list).reshape(-1)
+    sentinel = flat.size
+    listed = slots < sentinel
+    ids = np.arange(slots.size)
+    np.testing.assert_array_equal(flat[slots[listed]], ids[listed])
+    assert set(ids[listed]) == set(flat[flat < slots.size])
+    alive = np.asarray(pool.alive)
+    assert np.all(slots[~alive] == sentinel)
+    dropped = alive & ~listed
+    assert bool(index.overflowed) and dropped.any()
+    assert np.all(slots[dropped] == sentinel)
 
 
 def test_sort_agents_lowers_without_hlo_sort():

@@ -83,6 +83,7 @@ def test_cell_list_force_compiles(one_chip):
         _sds(one_chip, (n, 3), jnp.float32),
         _sds(one_chip, (n,), jnp.float32),
         _sds(one_chip, (n_cells, m), jnp.int32),
+        _sds(one_chip, (n,), jnp.int32),
         dims, interpret=False,
     ).compile()
     _assert_mosaic(compiled)

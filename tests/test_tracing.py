@@ -94,7 +94,8 @@ def test_compiled_cell_list_force_names_its_stages():
     cell_list[slots] = np.arange(n)
     cell_list = jnp.asarray(cell_list.reshape(-1, m))
     text = cf_ops.cell_list_force.lower(
-        pos, rad, cell_list, dims).compile().as_text()
+        pos, rad, cell_list, jnp.asarray(slots, jnp.int32), dims
+    ).compile().as_text()
     found = scopes(text)
     for stage in (spans.CELL_GATHER, spans.CELL_KERNEL, spans.CELL_SCATTER):
         assert stage in found, stage
